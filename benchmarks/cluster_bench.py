@@ -247,9 +247,8 @@ def run_speculative(args) -> dict:
     k+1-token verify forward streams the target's weights ONCE where plain
     offload streams them k+1 times."""
     from repro.config import PolicyConfig, SpecConfig, two_tier_topology
-    from repro.configs import reduced_config
-    from repro.models import build_model
-    from repro.serving.engine import TierEngine
+    from repro.serving.pool import tier_model_configs
+    from repro.serving.tiers import build_cluster_engines
 
     n = 3 if args.smoke else 6
     max_new = 16 if args.smoke else 48
@@ -260,16 +259,11 @@ def run_speculative(args) -> dict:
     sv = ServingConfig(max_batch=1, max_seq=256)
 
     def engines_for(topo):
-        out = {}
-        for i, tier in enumerate(topo.tiers):
-            cfg = reduced_config(tier.model).replace(dtype="float32")
-            if tier.name == "cloud":
-                cfg = cfg.replace(num_layers=12, d_model=384, num_heads=8,
-                                  num_kv_heads=4, d_ff=1536)
-            model = build_model(cfg)
-            out[tier.name] = TierEngine(
-                model, model.init(jax.random.PRNGKey(i)), sv)
-        return out
+        cfgs = tier_model_configs(topo)
+        cfgs["cloud"] = cfgs["cloud"].replace(
+            num_layers=12, d_model=384, num_heads=8, num_kv_heads=4,
+            d_ff=1536)
+        return build_cluster_engines(topo, sv, configs=cfgs)
     workload = [(0.05 * i, f"Request {i}: summarize the Report. "
                  + "and weigh every Detail carefully. " * 10)
                 for i in range(n)]

@@ -1,9 +1,13 @@
 """Flash-decode Pallas TPU kernel: one query token vs a long KV cache.
 
-Grid (B, K, nT): KV-length blocks innermost; running (m, l, acc) for the G
-query heads of one KV head live in VMEM scratch. Ring-buffer caches are
-handled by masking on ABSOLUTE slot positions (pos_cache), exactly like the
-XLA reference — empty slots carry position -1 and are masked out.
+Grid (B, nT): KV-length blocks innermost. Each grid step streams one
+(block_t, K, hd) block holding every KV head, so the block's last two
+dimensions are the array's own (K, hd) and any K tiles on the chip; the
+G query heads of each KV head keep their running (m, l, acc) in VMEM
+scratch. Ring-buffer caches are handled by masking on ABSOLUTE slot
+positions (pos_cache), exactly like the XLA reference — empty slots carry
+position -1 and are masked out. The query position rides in SMEM as a
+scalar-prefetch operand.
 
 Latency note: decode attention is memory-bound (reads the whole KV cache,
 does O(1) FLOPs per byte); the win of the kernel is fusing mask+softmax+
@@ -24,10 +28,11 @@ from repro.kernels.runtime import auto_interpret
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, posq_ref, posc_ref, o_ref,
+def _kernel(posq_ref, q_ref, k_ref, v_ref, posc_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, window: Optional[int],
             n_t: int):
-    it = pl.program_id(2)
+    b = pl.program_id(0)
+    it = pl.program_id(1)
 
     @pl.when(it == 0)
     def _init():
@@ -35,44 +40,76 @@ def _kernel(q_ref, k_ref, v_ref, posq_ref, posc_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)       # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bt, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    pos_q = posq_ref[0, 0]                     # scalar int32
-    pos_c = posc_ref[0, :]                     # (bt,) int32
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    d = pos_q - pos_c  # (bt,)
+    pos_q = posq_ref[b]                        # SMEM scalar
+    pos_c = posc_ref[0, 0]                     # (1, bt) int32
+    d = pos_q - pos_c
     ok = (pos_c >= 0) & (d >= 0)
     if window is not None:
         ok &= d < window
-    s = jnp.where(ok[None, :], s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    acc_ref[...] = (acc_ref[...] * corr[:, None]
-                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32))
-    m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
+    for j in range(q_ref.shape[1]):            # KV heads (static)
+        k = k_ref[0, :, j, :]                  # (bt, hd)
+        v = v_ref[0, :, j, :]
+        q = q_ref[0, j].astype(k.dtype)        # (G, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok, s, NEG_INF)          # (G, bt)
+        m_prev = m_ref[j]                      # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        acc_ref[j] = acc_ref[j] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[j] = m_new
+        l_ref[j] = l_ref[j] * corr + jnp.sum(p, axis=1, keepdims=True)
 
     @pl.when(it == n_t - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_kernel(pages_ref, q_ref, k_ref, v_ref, posq_ref, posc_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale: float,
-                  window: Optional[int], n_t: int):
+def _paged_kernel(pages_ref, posq_ref, *refs, **kw):
     # the page table is consumed by the k/v index_maps (scalar prefetch);
     # the block body is the exact dense flash-decode update
     del pages_ref
-    _kernel(q_ref, k_ref, v_ref, posq_ref, posc_ref, o_ref,
-            acc_ref, m_ref, l_ref, scale=scale, window=window, n_t=n_t)
+    _kernel(posq_ref, *refs, **kw)
+
+
+def _call(kernel, prefetch, qg, k, v, posc, kv_block, kv_map, window,
+          interpret):
+    """pallas_call plumbing shared by the dense and paged kernels: grid
+    (B, nT) over ``posc``'s (B, nT, 1, bt) position blocks, scalar-prefetch
+    operands ``prefetch`` first, and the kv blocks placed by ``kv_map``."""
+    b, kh, g, hd = qg.shape
+    n_t = posc.shape[1]
+    q_spec = pl.BlockSpec((1, kh, g, hd), lambda b_, it, *_: (b_, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, n_t),
+        in_specs=[
+            q_spec,
+            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec((1, 1, 1, posc.shape[-1]),
+                         lambda b_, it, *_: (b_, it, 0, 0)),
+        ],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((kh, g, hd), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, scale=hd ** -0.5, window=window, n_t=n_t),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, qg, k, v, posc)
 
 
 def decode_attention_pallas_paged(q: jax.Array, k_pool: jax.Array,
@@ -106,40 +143,13 @@ def decode_attention_pallas_paged(q: jax.Array, k_pool: jax.Array,
     if t < n_p * page:  # pad the ragged tail; pos -1 masks the pad entries
         pos_cache = jnp.pad(pos_cache, ((0, 0), (0, n_p * page - t)),
                             constant_values=-1)
-    scale = hd ** -0.5
-
     qg = q[:, 0].reshape(b, kh, g, hd)
-    posq2 = pos_q.reshape(b, 1).astype(jnp.int32)
-    posc = pos_cache
-
-    kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               n_t=n_p)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kh, n_p),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda pg, b_, kh_, it: (b_, kh_, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda pg, b_, kh_, it: (pg[b_, it], 0, kh_, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda pg, b_, kh_, it: (pg[b_, it], 0, kh_, 0)),
-            pl.BlockSpec((1, 1), lambda pg, b_, kh_, it: (b_, 0)),
-            pl.BlockSpec((1, page), lambda pg, b_, kh_, it: (b_, it)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda pg, b_, kh_, it: (b_, kh_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
-        interpret=interpret,
-    )(pages.astype(jnp.int32), qg, k_pool, v_pool, posq2, posc)
+    posc = pos_cache.astype(jnp.int32).reshape(b, n_p, 1, page)
+    out = _call(_paged_kernel,
+                (pages.astype(jnp.int32), pos_q.astype(jnp.int32)),
+                qg, k_pool, v_pool, posc, (1, page, kh, hd),
+                lambda b_, it, pg, pq: (pg[b_, it], 0, 0, 0), window,
+                interpret)
     return out.reshape(b, 1, h, hd)
 
 
@@ -181,29 +191,10 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
                                 constant_values=-1)
             t += tail
     n_t = t // block_t
-    scale = hd ** -0.5
 
     qg = q[:, 0].reshape(b, kh, g, hd)  # (B,K,G,hd), head h = kh_idx*g + g_idx
-    posq2 = pos_q.reshape(b, 1).astype(jnp.int32)
-
-    kernel = functools.partial(_kernel, scale=scale, window=window, n_t=n_t)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, kh, n_t),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b_, kh_, it: (b_, kh_, 0, 0)),
-            pl.BlockSpec((1, block_t, 1, hd), lambda b_, kh_, it: (b_, it, kh_, 0)),
-            pl.BlockSpec((1, block_t, 1, hd), lambda b_, kh_, it: (b_, it, kh_, 0)),
-            pl.BlockSpec((1, 1), lambda b_, kh_, it: (b_, 0)),
-            pl.BlockSpec((1, block_t), lambda b_, kh_, it: (b_, it)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, kh_, it: (b_, kh_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, k_cache, v_cache, posq2, pos_cache)
+    posc = pos_cache.astype(jnp.int32).reshape(b, n_t, 1, block_t)
+    out = _call(_kernel, (pos_q.astype(jnp.int32),), qg, k_cache, v_cache,
+                posc, (1, block_t, kh, hd),
+                lambda b_, it, pq: (b_, it, 0, 0), window, interpret)
     return out.reshape(b, 1, h, hd)

@@ -7,8 +7,10 @@ written once on the last KV step. GQA is expressed in the K/V index_maps
 (query head h reads KV head h // group_size) so KV blocks are fetched once
 per group, not per query head.
 
-Block shapes default to (128, head_dim): 128 is MXU/VREG aligned, and
-head_dim is padded to a lane multiple by the wrapper in ops.py.
+The wrapper moves the head axis ahead of the sequence axis, so every block
+is a (block, head_dim) tile in its last two dimensions. Block shapes default
+to (128, head_dim): 128 is MXU/VREG aligned, and head_dim is padded to a
+lane multiple by the wrapper in ops.py.
 """
 from __future__ import annotations
 
@@ -46,9 +48,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, hd)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -62,21 +64,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
         m_prev = m_ref[...]
         l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)
+        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
 
     @pl.when(ik == n_k - 1)
     def _finalize():
-        o_ref[0, :, 0, :] = (acc_ref[...] /
-                             jnp.maximum(l_ref[...], 1e-30)[:, None]
-                             ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] /
+                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -106,18 +107,20 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         kernel,
         grid=(b, h, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b_, h_, iq, ik: (b_, ik, h_ // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b_, h_, iq, ik: (b_, ik, h_ // g, 0)),
+            pl.BlockSpec((1, 1, block_q, hd), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+                               lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
-    return out
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))  # (B, heads, S, hd)
+    return out.transpose(0, 2, 1, 3)

@@ -31,11 +31,10 @@ import numpy as np
 from repro.config import (TOPOLOGIES, ResilienceConfig, ServingConfig,
                           SpecConfig, get_topology)
 from repro.data.synthetic import make_image
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.faults import FaultPlan
 from repro.serving.tiers import (ClusterServer, build_cluster_engines,
                                  build_engine_pools)
-
-build_engines = build_cluster_engines  # legacy alias
 
 
 def parse_replicas(specs) -> dict:
@@ -178,6 +177,7 @@ def main() -> None:
                          "(0 = event-driven: sleep until the next "
                          "scheduled event)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sv = ServingConfig(max_batch=args.max_batch, max_seq=args.max_seq,
                        fused_steps=args.fused_steps,
@@ -249,7 +249,7 @@ def main() -> None:
         rep_str = " ".join(f"{t}x{n}" for t, n in sorted(counts.items()))
         print(f"replicas: {rep_str} | transport {args.transport}")
     else:
-        engines = build_engines(topo, sv)
+        engines = build_cluster_engines(topo, sv)
     server = ClusterServer(engines, topology=topo,
                            hedge_after_s=args.hedge_after,
                            fail_rate=args.fail_rate, migrate=args.migrate,
@@ -396,6 +396,22 @@ def main() -> None:
         print(f"  rid={r.rid} tier={r.tier:9s} routes={r.routes} "
               f"lat={r.latency_s:.3f}s ttft={r.ttft_s:.3f}s{flags}")
     server.close()  # joins process-transport workers; no-op for local
+    faults = bool(args.fault_plan or args.byzantine or args.fail_rate > 0)
+    status = exit_status(results, faults)
+    if status:
+        raise SystemExit(status)
+
+
+def exit_status(results, faults_requested: bool) -> int:
+    """1 when a request ended failed in a run that injected no faults (a
+    shed request was turned away by the ``--shed`` policy, not lost)."""
+    lost = [r.rid for r in results
+            if r.failed and r.fail_reason != "shed"]
+    if lost and not faults_requested:
+        print(f"FAILED: {len(lost)} request(s) never completed "
+              f"(rids {sorted(lost)}) with no faults injected")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":

@@ -25,18 +25,7 @@ from repro.config import ModelConfig
 from repro.models.layers import activation_fn, is_gated
 from repro.models.spec import ParamSpec
 
-if hasattr(jax, "shard_map"):  # jax >= 0.7: top-level API, check_vma kwarg
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:  # older jax: experimental module, replication check is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    _shard_map = functools.partial(_shard_map_legacy, check_rep=False)
-
-if hasattr(jax.lax, "axis_size"):
-    _axis_size = jax.lax.axis_size
-else:  # older jax: derive the mesh-axis size via a collective
-    def _axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def moe_specs(cfg: ModelConfig, prefix_axes=()) -> dict:
@@ -198,7 +187,7 @@ def moe_dropping_local(params: dict, xt: jax.Array, cfg: ModelConfig,
     tp = 1
     e_lo = 0
     if model_axis is not None:
-        tp = _axis_size(model_axis)
+        tp = jax.lax.axis_size(model_axis)
         e_lo = jax.lax.axis_index(model_axis) * e_local
     num_experts = e_local * tp
     ids, w, probs = _router_topk(xt, params["router"], cfg.top_k)
@@ -286,7 +275,7 @@ def moe_decode_2d_local(params: dict, xt: jax.Array, cfg: ModelConfig,
     """
     t, d = xt.shape
     e_local = params["wi"].shape[0]
-    dp = _axis_size(data_axis)
+    dp = jax.lax.axis_size(data_axis)
     e_lo = jax.lax.axis_index(data_axis) * e_local
     num_experts = e_local * dp
     ids, w, _ = _router_topk(xt, params["router"], cfg.top_k)

@@ -273,7 +273,19 @@ class SlotPayload:
 
 class TierEngine:
     def __init__(self, model, params, serving: ServingConfig = ServingConfig(),
-                 eos_id: int = 2, sample_temp: float = 0.0, seed: int = 0):
+                 eos_id: int = 2, sample_temp: float = 0.0, seed: int = 0,
+                 device=None):
+        """``device`` pins the engine — params, KV cache, sampling keys —
+        to one ``jax.Device`` (replicas of a pool, one per chip); every
+        jitted step then runs there. None keeps JAX's default device."""
+        self.device = device
+        with jax.default_device(device):
+            self._build(model, params, serving, eos_id, sample_temp, seed)
+        if device is not None:
+            self.params, self.cache, self._keys = jax.device_put(
+                (self.params, self.cache, self._keys), device)
+
+    def _build(self, model, params, serving, eos_id, sample_temp, seed):
         # shallow copy: the engine sets per-engine decode flags below and
         # must not leak them into a model object shared with other engines
         model = copy.copy(model)
@@ -829,7 +841,7 @@ class TierEngine:
         self._install_rows(slot, rows)
         self.slots[slot] = self._copy_seq(payload.seq)
         self.positions[slot] = payload.position
-        self._keys = self._keys.at[slot].set(jnp.asarray(payload.key))
+        self._keys = self._keys.at[slot].set(self._put(payload.key))
         self._slot_prompt[slot] = (None if payload.prompt_tokens is None
                                    else np.asarray(payload.prompt_tokens))
         self._slot_extras_fp[slot] = payload.extras_fp
@@ -1825,7 +1837,7 @@ class TierEngine:
             _, redo = chunk(self.params, redo, rb, teff)
             self._insert_cache(redo, slot)
         if self.temp > 0:
-            self._keys = self._keys.at[slot].set(jnp.asarray(key))
+            self._keys = self._keys.at[slot].set(self._put(key))
         self.positions[slot] = commit_end
         self._spec_resize_pages(slot, commit_end)  # decref rejected tail
         self.accepted_tokens += m - 1
@@ -2190,14 +2202,19 @@ class TierEngine:
             }
         return out
 
+    def _put(self, x):
+        """Host array(s) -> this engine's device (JAX's default when the
+        engine is not pinned)."""
+        return jax.device_put(x, self.device)
+
     def restore(self, snap: dict) -> None:
-        self.cache = jax.tree.map(jnp.asarray, snap["cache"])
+        self.cache = self._put(snap["cache"])
         self.slots = [self._copy_seq(s) for s in snap["slots"]]
         self.positions = snap["positions"].copy()
         self.waiting = list(snap["waiting"])
         self.steps = snap["steps"]
         if "keys" in snap:
-            self._keys = jnp.asarray(snap["keys"])
+            self._keys = self._put(snap["keys"])
         b = len(self.slots)
         self._slot_prompt = [None if p is None else p.copy()
                              for p in snap.get("slot_prompt",

@@ -399,20 +399,55 @@ class EnginePool:
         return out
 
 
-def build_engine_pools(topology, serving, dtype: str = "float32",
+def tier_model_configs(topology, configs=None) -> Dict[str, object]:
+    """The ``ModelConfig`` each topology tier serves.
+
+    ``configs`` maps tier name -> config and is used as given: the
+    engine's dtype is the config's own (bf16 for the published configs).
+    Without it every tier serves its model's reduced float32 config — the
+    CPU-sized build the tests and the CPU benchmarks run."""
+    from repro.configs import reduced_config  # local: no cycle
+
+    if configs is not None:
+        missing = [t.name for t in topology.tiers if t.name not in configs]
+        if missing:
+            raise ValueError(f"no model config for tiers {missing}")
+        return {t.name: configs[t.name] for t in topology.tiers}
+    return {t.name: reduced_config(t.model).replace(dtype="float32")
+            for t in topology.tiers}
+
+
+def build_tier_model(cfg, seed: int):
+    """(model, params) for one tier: random weights from ``PRNGKey(seed)``
+    (tier *i* of a topology uses seed *i*)."""
+    import jax
+
+    from repro.models import build_model
+
+    model = build_model(cfg)
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
+def build_engine_pools(topology, serving, configs=None,
                        replicas: Optional[Dict[str, int]] = None,
                        transport: str = "local",
                        serving_overrides: Optional[Dict[str, object]] = None,
-                       ) -> Dict[str, EnginePool]:
+                       devices=None) -> Dict[str, EnginePool]:
     """One :class:`EnginePool` per topology tier.
 
+    ``configs`` is the ``{tier: ModelConfig}`` mapping of
+    :func:`tier_model_configs` (default: reduced float32 configs).
     Replica counts default to each tier's ``TierSpec.servers`` (the
     topology's declared server count, finally instantiated); ``replicas``
     overrides per tier name. Local replicas of a tier share ONE model +
     params build (same ``PRNGKey(tier_index)`` seed as
     ``build_cluster_engines``), so replicated decoding is token-identical
     to the single-engine path at temp=0; process replicas rebuild the
-    same params from the same seed in their worker.
+    same reduced params from the same seed in their worker.
+
+    ``devices`` places local replica *r* of every tier — its params and
+    KV cache — on ``devices[r]``; a tier with more replicas than devices
+    is refused (default: JAX's default device for all).
 
     ``serving_overrides`` swaps the shared :class:`ServingConfig` per tier
     name — heterogeneous tiers rarely share slot budgets (an edge device
@@ -421,25 +456,30 @@ def build_engine_pools(topology, serving, dtype: str = "float32",
     if transport not in ("local", "process"):
         raise ValueError(f"unknown transport {transport!r} "
                          f"(expected 'local' or 'process')")
+    if transport == "process" and configs is not None:
+        raise ValueError("process replicas rebuild reduced configs in "
+                         "their worker; explicit configs need the local "
+                         "transport")
+    cfgs = tier_model_configs(topology, configs)
     pools: Dict[str, EnginePool] = {}
     for i, tier in enumerate(topology.tiers):
         n = max(1, int((replicas or {}).get(tier.name, tier.servers)))
         sv = (serving_overrides or {}).get(tier.name, serving)
         if transport == "process":
             trs: List = [ProcessTransport(ReplicaSpec(
-                model=tier.model, serving=sv, dtype=dtype,
-                param_seed=i, name=f"{tier.name}/{r}"))
+                model=tier.model, serving=sv, param_seed=i,
+                name=f"{tier.name}/{r}"))
                 for r in range(n)]
         else:
-            from repro.configs import reduced_config  # local: no cycle
-            from repro.models import build_model
-            from repro.serving.engine import TierEngine
-            import jax
+            from repro.serving.engine import TierEngine  # local: no cycle
 
-            cfg = reduced_config(tier.model).replace(dtype=dtype)
-            model = build_model(cfg)
-            params = model.init(jax.random.PRNGKey(i))
-            trs = [LocalTransport(TierEngine(model, params, sv))
-                   for _ in range(n)]
+            if devices and len(devices) < n:
+                raise ValueError(
+                    f"tier {tier.name!r} has {n} replicas but only "
+                    f"{len(devices)} devices: one replica per device")
+            model, params = build_tier_model(cfgs[tier.name], i)
+            trs = [LocalTransport(TierEngine(
+                model, params, sv, device=devices[r] if devices else None))
+                for r in range(n)]
         pools[tier.name] = EnginePool(tier.name, trs)
     return pools

@@ -31,17 +31,17 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-import jax
 import numpy as np
 
-from repro.config import (ClusterTopology, ResilienceConfig, ServingConfig,
-                          SpecConfig, two_tier_topology)
+from repro.config import (ClusterTopology, ModelConfig, ResilienceConfig,
+                          ServingConfig, SpecConfig, two_tier_topology)
 from repro.core.request import ModalityInput, Request
 from repro.core.scheduler import MoAOffScheduler
 from repro.data.tokenizer import ToyTokenizer
 from repro.serving.engine import TierEngine
 from repro.serving.faults import FaultPlan
-from repro.serving.pool import EnginePool, build_engine_pools  # noqa: F401
+from repro.serving.pool import (EnginePool, build_engine_pools,  # noqa: F401
+                                build_tier_model, tier_model_configs)
 from repro.serving.runtime import ClusterRuntime, LiveBackend
 
 
@@ -69,19 +69,18 @@ class ServedResult:
 
 def build_cluster_engines(topology: ClusterTopology,
                           serving: ServingConfig,
-                          dtype: str = "float32") -> Dict[str, TierEngine]:
-    """One reduced-model ``TierEngine`` per topology tier (deterministic
-    per-tier param seeds) — the canonical construction shared by the
-    launcher, the cluster benchmark and the tests."""
-    from repro.configs import reduced_config  # local imports, no cycle
-    from repro.models import build_model
-
+                          configs: Optional[Dict[str, ModelConfig]] = None
+                          ) -> Dict[str, TierEngine]:
+    """One ``TierEngine`` per topology tier (deterministic per-tier param
+    seeds) — the canonical construction shared by the launcher, the
+    cluster benchmark, the chip smoke and the tests. ``configs`` maps tier
+    name -> ``ModelConfig`` (the engine runs in the config's dtype); by
+    default every tier serves its reduced float32 config."""
+    cfgs = tier_model_configs(topology, configs)
     engines = {}
     for i, tier in enumerate(topology.tiers):
-        cfg = reduced_config(tier.model).replace(dtype=dtype)
-        model = build_model(cfg)
-        engines[tier.name] = TierEngine(
-            model, model.init(jax.random.PRNGKey(i)), serving)
+        model, params = build_tier_model(cfgs[tier.name], i)
+        engines[tier.name] = TierEngine(model, params, serving)
     return engines
 
 

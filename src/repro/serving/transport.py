@@ -270,9 +270,8 @@ class ReplicaSpec:
     process replica serves the SAME weights as its local twin and temp=0
     decoding is token-identical across transports.
     """
-    model: str
+    model: str  # served at its reduced float32 config (CPU workers)
     serving: ServingConfig
-    dtype: str = "float32"
     param_seed: int = 0
     eos_id: int = 2
     sample_temp: float = 0.0
@@ -544,7 +543,7 @@ def _worker_main(conn, spec_raw: bytes) -> None:
         from repro.models import build_model
         from repro.serving.engine import TierEngine
 
-        cfg = reduced_config(spec.model).replace(dtype=spec.dtype)
+        cfg = reduced_config(spec.model).replace(dtype="float32")
         model = build_model(cfg)
         eng = TierEngine(model, model.init(jax.random.PRNGKey(spec.param_seed)),
                          spec.serving, eos_id=spec.eos_id,
@@ -712,6 +711,16 @@ class ProcessTransport:
     def __init__(self, spec: ReplicaSpec, start_timeout_s: float = 120.0,
                  rpc_timeout_s: float = 60.0,
                  max_frame_bytes: int = DEFAULT_MAX_FRAME_BYTES):
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            # the worker runs on the CPU, and a chip belongs to one
+            # process: serving here would quietly leave the chip idle
+            raise TransportError(
+                f"process replicas run CPU-only workers, but this process "
+                f"holds the {backend} backend; use the local transport "
+                f"(one process drives every chip)")
         self.spec = spec
         self.alive = True
         self.rpc_timeout_s = rpc_timeout_s
@@ -739,7 +748,7 @@ class ProcessTransport:
         # parent-side model config twin (for patch geometry / embed bytes
         # without a round trip) — the worker builds the same reduced config
         from repro.configs import reduced_config
-        self.cfg = reduced_config(spec.model).replace(dtype=spec.dtype)
+        self.cfg = reduced_config(spec.model).replace(dtype="float32")
         ctx = mp.get_context("spawn")
         self._conn, child = ctx.Pipe(duplex=True)
         self._proc = ctx.Process(

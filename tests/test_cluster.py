@@ -1,21 +1,17 @@
 """Multi-tier cluster runtime tests: N-tier simulator routing invariants,
 two-tier backward compatibility, the live ClusterServer, and regressions for
 the simulator accounting/hedging fixes."""
-import jax
 import numpy as np
 import pytest
 
 from repro.config import (ClusterTopology, PolicyConfig, ServingConfig,
                           SimConfig, TierSpec, get_topology,
                           two_tier_topology)
-from repro.configs import reduced_config
 from repro.core import SystemState, make_policy
 from repro.core.request import Job, RequestRecord
 from repro.data.synthetic import RequestGenerator
-from repro.models import build_model
-from repro.serving.engine import TierEngine
 from repro.serving.simulator import ClusterSimulator, EdgeCloudSimulator
-from repro.serving.tiers import ClusterServer
+from repro.serving.tiers import ClusterServer, build_cluster_engines
 
 
 def _run_topology_sim(topology, policy="moa-off", n=150, rate=4.0, seed=0,
@@ -239,13 +235,7 @@ def test_hedged_straggler_produces_single_outcome():
 def test_cluster_server_three_tiers_live():
     sv = ServingConfig(max_batch=2, max_seq=96)
     topo = get_topology("edge-regional-cloud")
-    engines = {}
-    for i, tier in enumerate(topo.tiers):
-        cfg = reduced_config(tier.model).replace(dtype="float32")
-        model = build_model(cfg)
-        engines[tier.name] = TierEngine(
-            model, model.init(jax.random.PRNGKey(i)), sv)
-    srv = ClusterServer(engines, topology=topo)
+    srv = ClusterServer(build_cluster_engines(topo, sv), topology=topo)
     rng = np.random.default_rng(0)
     from repro.data.synthetic import make_image
 

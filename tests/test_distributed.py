@@ -111,7 +111,7 @@ _SUBPROC_DRYRUN = textwrap.dedent("""
     import jax, json
     import repro.launch.mesh as lm
     # shrink the production mesh for the in-CI variant
-    lm.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
+    lm.make_production_mesh = lambda multi_pod=False: lm.auto_mesh(
         (2, 2, 2) if multi_pod else (2, 4),
         ("pod", "data", "model") if multi_pod else ("data", "model"))
     import repro.launch.dryrun as dr

@@ -121,6 +121,33 @@ def test_decode_attention_vs_ref(t, h, kh, hd, fill):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("b,h,kh,page,n_pt,width,window", [
+    (2, 8, 2, 16, 4, 64, None),   # GQA, every logical row in the table
+    (3, 4, 1, 16, 4, 50, None),   # MQA, ragged last page (pos width 50)
+    (2, 4, 2, 32, 3, 96, 40),     # sliding window
+])
+def test_decode_attention_paged_vs_ref(b, h, kh, page, n_pt, width, window):
+    """Pages are read THROUGH each row's table out of a shared pool, so the
+    kernel must equal dense decode over the gathered context."""
+    hd, n_pool = 64, 1 + b * n_pt
+    q = _rand((b, 1, h, hd))
+    kp = _rand((n_pool, page, kh, hd))
+    vp = _rand((n_pool, page, kh, hd))
+    pages = np.stack([RNG.permutation(np.arange(1, n_pool))[:n_pt]
+                      for _ in range(b)]).astype(np.int32)
+    fill = RNG.integers(1, width + 1, size=b)
+    pos = np.where(np.arange(width)[None] < fill[:, None],
+                   np.arange(width)[None], -1).astype(np.int32)
+    pq = jnp.asarray(fill - 1, jnp.int32)
+    got = ops.decode_attention_paged(q, kp, vp, jnp.asarray(pages), pq,
+                                     jnp.asarray(pos), window=window,
+                                     interpret=True)
+    ctx = lambda pool: pool[pages].reshape(b, n_pt * page, kh, hd)[:, :width]
+    want = ref.decode_attention_ref(q, ctx(kp), ctx(vp), pq, jnp.asarray(pos),
+                                    window=window)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
 def test_decode_attention_ring_buffer_semantics():
     """Slot order must not matter — only absolute positions."""
     b, t, kh, hd = 1, 128, 2, 32
