@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ComplexityConfig
 
 
@@ -36,12 +37,14 @@ def image_complexity(imgs: jax.Array,
     from repro.kernels import ops as kops
     from repro.kernels import ref as kref
 
-    if use_kernel:
-        stats = kops.image_stats(imgs, interpret=interpret)
-    else:
-        stats = kref.image_stats_batch_ref(imgs)
-    return kops.image_complexity_from_stats(stats, imgs.shape[1],
-                                            imgs.shape[2], cc)
+    with obs.span("moa.scheduler.score.stats"):
+        if use_kernel:
+            stats = kops.image_stats(imgs, interpret=interpret)
+        else:
+            stats = kref.image_stats_batch_ref(imgs)
+    with obs.span("moa.scheduler.score.features"):
+        return kops.image_complexity_from_stats(stats, imgs.shape[1],
+                                                imgs.shape[2], cc)
 
 
 def calibrate_percentiles(imgs: jax.Array,

@@ -105,7 +105,16 @@ class RequestRecord:
 
     ``events`` is the ordered (state, tier) trace — state names come from
     :data:`LIFECYCLE`; per-token streaming is deliberately NOT an event so
-    analytic and live traces stay comparable. ``done`` is the single
+    analytic and live traces stay comparable. ``times`` runs parallel to
+    it: when each event was marked, on the backend's clock (virtual
+    seconds analytic, monotonic live), None where the marker had no time.
+    Live, ``arrival`` carries the request's due time, the marks made after
+    scoring (``routed``, ``encode:*``, ``enqueue``) the moment they were
+    made, and the rest the time their event was due. ``t_admit`` is the
+    moment the first attempt left a serving queue for its prefill; it is
+    a point in time only, not an event, so traces keep their states.
+    ``queue_s()`` is therefore the wait in the engines' queues alone,
+    scoring and dispatch left out. ``done`` is the single
     completion cell: whichever hedged twin finishes first flips it, and the
     loser's completion (or a replayed one after a fault restore) is dropped.
     """
@@ -113,6 +122,8 @@ class RequestRecord:
     rid: int
     done: bool = False
     events: List[Tuple[str, str]] = field(default_factory=list)
+    times: List[Optional[float]] = field(default_factory=list)
+    t_admit: Optional[float] = None
     ttft_s: float = 0.0
     wan_s: float = 0.0  # time spent on WAN links before first enqueue
     truncated: bool = False
@@ -128,8 +139,29 @@ class RequestRecord:
     accepted_tokens: int = 0
     outcome: Optional["Outcome"] = None
 
-    def mark(self, state: str, tier: str = "") -> None:
+    def mark(self, state: str, tier: str = "",
+             t: Optional[float] = None) -> None:
         self.events.append((state, tier))
+        self.times.append(t)
+
+    def admit(self, t: float) -> None:
+        if self.t_admit is None:
+            self.t_admit = t
+
+    def time_of(self, state: str) -> Optional[float]:
+        """When ``state`` was first marked (None if never, or untimed)."""
+        for (s, _), t in zip(self.events, self.times):
+            if s == state:
+                return t
+        return None
+
+    def queue_s(self) -> Optional[float]:
+        """Queue wait: admission less the first enqueue (None until both
+        are known)."""
+        t_enq = self.time_of("enqueue")
+        if t_enq is None or self.t_admit is None:
+            return None
+        return self.t_admit - t_enq
 
     def trace(self) -> Tuple[Tuple[str, str], ...]:
         return tuple(self.events)

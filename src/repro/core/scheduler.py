@@ -12,10 +12,12 @@ import time
 import warnings
 from typing import Dict, List, Optional
 
+import jax.numpy as jnp
 import numpy as np
 
 import dataclasses
 
+from repro import obs
 from repro.config import ComplexityConfig, PolicyConfig, SpecConfig
 from repro.core import complexity as cx
 from repro.core.policy import OffloadingPolicy
@@ -47,49 +49,57 @@ class MoAOffScheduler:
         metadata counts the data pipeline attached (same formulas)."""
         t0 = time.perf_counter()
         scores: Dict[str, float] = {}
-        for name, mod in request.modalities.items():
-            if mod.complexity is not None:
-                scores[name] = float(mod.complexity)
-                continue
-            if mod.kind == "image":
-                if mod.data is not None:
-                    img = np.asarray(mod.data, np.float32)[None]
-                    out = cx.image_complexity(img, self.cc,
-                                              use_kernel=self.use_kernel)
-                    scores[name] = float(out["c_img"][0])
-                else:
-                    h = mod.meta.get("h", 512)
-                    w = mod.meta.get("w", 512)
-                    base = min(1.0, (h * w) / (self.cc.ref_h * self.cc.ref_w))
-                    scores[name] = (self.cc.w_res * base
-                                    + (1 - self.cc.w_res)
-                                    * mod.meta.get("content_c", 0.5))
-            elif mod.kind == "text":
-                out = cx.text_complexity_from_counts(
-                    mod.meta.get("tokens", 0), mod.meta.get("entities", 0),
-                    mod.meta.get("sentences", 1), self.cc)
-                scores[name] = float(out["c_text"])
-            elif mod.kind == "audio":
-                if mod.data is not None:
-                    out = cx.audio_complexity(np.asarray(mod.data)[None], self.cc)
-                    scores[name] = float(out["c_audio"][0])
-                else:
-                    scores[name] = float(mod.meta.get("content_c", 0.5))
-            mod.complexity = scores[name]
+        image = request.modalities.get("image")
+        with obs.span("moa.scheduler.score", rid=request.rid,
+                      shape=None if image is None else np.shape(image.data)):
+            for name, mod in request.modalities.items():
+                if mod.complexity is not None:
+                    scores[name] = float(mod.complexity)
+                    continue
+                if mod.kind == "image":
+                    scores[name] = self._image_score(mod)
+                elif mod.kind == "text":
+                    out = cx.text_complexity_from_counts(
+                        mod.meta.get("tokens", 0),
+                        mod.meta.get("entities", 0),
+                        mod.meta.get("sentences", 1), self.cc)
+                    scores[name] = float(out["c_text"])
+                elif mod.kind == "audio":
+                    if mod.data is not None:
+                        out = cx.audio_complexity(
+                            np.asarray(mod.data)[None], self.cc)
+                        scores[name] = float(out["c_audio"][0])
+                    else:
+                        scores[name] = float(mod.meta.get("content_c", 0.5))
+                mod.complexity = scores[name]
         self.score_time_s += time.perf_counter() - t0
         self.n_scored += 1
         return scores
+
+    def _image_score(self, mod: ModalityInput) -> float:
+        if mod.data is None:
+            h = mod.meta.get("h", 512)
+            w = mod.meta.get("w", 512)
+            base = min(1.0, (h * w) / (self.cc.ref_h * self.cc.ref_w))
+            return (self.cc.w_res * base
+                    + (1 - self.cc.w_res) * mod.meta.get("content_c", 0.5))
+        with obs.span("moa.scheduler.score.prepare"):
+            img = jnp.asarray(np.asarray(mod.data, np.float32)[None])
+        out = cx.image_complexity(img, self.cc, use_kernel=self.use_kernel)
+        with obs.span("moa.scheduler.score.read"):
+            return float(out["c_img"][0])
 
     # -- routing ---------------------------------------------------------------
 
     def route(self, request: Request,
               state: Optional[SystemState] = None) -> Decision:
         scores = self.score(request)
-        st = state or self.estimator.snapshot()
-        decision = self.policy.decide(request, scores, st)
-        if self.spec is not None:
-            decision = self._maybe_speculate(decision, st)
-        self.policy.update(st)
+        with obs.span("moa.scheduler.decide", rid=request.rid):
+            st = state or self.estimator.snapshot()
+            decision = self.policy.decide(request, scores, st)
+            if self.spec is not None:
+                decision = self._maybe_speculate(decision, st)
+            self.policy.update(st)
         return decision
 
     def _maybe_speculate(self, decision: Decision,

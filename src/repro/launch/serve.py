@@ -13,7 +13,9 @@ enable the tier-health circuit breaker, retry backoff and deadline-aware
 load shedding. ``--byzantine`` adds message-level wire faults (frame
 corruption, drops, dups, reorders — all detected by checksums and the
 exactly-once delivery ledger) and ``--audit`` runs the runtime invariant
-auditor at completion.
+auditor at completion. ``--trace-dir`` turns the program's host spans on
+(``repro.obs``), profiles the serve with ``jax.profiler`` into that
+directory, and prints each span name's count, total and self time.
 
 PYTHONPATH=src python -m repro.launch.serve --requests 16 --bandwidth 300e6
 PYTHONPATH=src python -m repro.launch.serve --topology edge-regional-cloud
@@ -26,8 +28,10 @@ import dataclasses
 import os
 import time
 
+import jax
 import numpy as np
 
+from repro import obs
 from repro.config import (TOPOLOGIES, ResilienceConfig, ServingConfig,
                           SpecConfig, get_topology)
 from repro.data.synthetic import make_image
@@ -47,6 +51,23 @@ def parse_replicas(specs) -> dict:
                              f"got {spec!r}")
         out[tier] = int(n)
     return out
+
+
+def span_table(spans):
+    """``[(name, count, total_s, self_s)]`` per span name, most self time
+    first; self time is the total less the time of the spans directly
+    under it."""
+    rows = {}
+    for sp in spans:
+        r = rows.setdefault(sp.name, [0, 0.0, 0.0])
+        r[0] += 1
+        r[1] += sp.t1 - sp.t0
+        r[2] += sp.t1 - sp.t0
+    for sp in spans:
+        if sp.parent in rows:
+            rows[sp.parent][2] -= sp.t1 - sp.t0
+    return sorted(((n, c, t, s) for n, (c, t, s) in rows.items()),
+                  key=lambda r: -r[3])
 
 
 def main() -> None:
@@ -176,6 +197,10 @@ def main() -> None:
                     help="idle-wait cap in seconds for the serving loop "
                          "(0 = event-driven: sleep until the next "
                          "scheduled event)")
+    ap.add_argument("--trace-dir", default=None, metavar="DIR",
+                    help="turn the program's host spans on, write a "
+                         "jax.profiler trace of the serve to DIR and print "
+                         "count, total and self time per span name")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -261,6 +286,9 @@ def main() -> None:
                            audit=args.audit)
 
     rng = np.random.default_rng(args.seed)
+    if args.trace_dir:
+        obs.enable()
+        jax.profiler.start_trace(args.trace_dir)
     t0 = time.perf_counter()
     if args.sessions > 0:
         system = "you are a Helpful assistant; answer with Care. "
@@ -289,6 +317,13 @@ def main() -> None:
                           slo_s=args.slo, delay_s=delay)
         results = server.run()
     wall = time.perf_counter() - t0
+    if args.trace_dir:
+        jax.profiler.stop_trace()
+        obs.disable()
+        print(f"spans (profile in {args.trace_dir}): name count total_s "
+              f"self_s")
+        for name, n, total, own in span_table(obs.take()):
+            print(f"  {name:32s} {n:6d} {total:9.4f} {own:9.4f}")
     per_tier = {}
     for r in results:
         per_tier[r.tier] = per_tier.get(r.tier, 0) + 1
@@ -299,6 +334,12 @@ def main() -> None:
     print(f"served {len(results)} requests | {split} | mean latency "
           f"{lat:.3f}s | mean ttft {ttft:.3f}s | {on_time}/{len(results)} "
           f"within SLO | goodput {on_time / max(wall, 1e-9):.2f} req/s")
+    waits = [w for w in (server.runtime.records[r.rid].queue_s()
+                         for r in results) if w is not None]
+    if waits:
+        p50, p95 = np.percentile(waits, [50, 95]) * 1e3
+        print(f"queue wait (enqueue to admission): p50 {p50:.1f} ms, "
+              f"p95 {p95:.1f} ms over {len(waits)} requests")
     hedged = sum(r.hedged for r in results)
     retries = sum(r.retries for r in results)
     trunc = sum(r.truncated for r in results)

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig, ServingConfig
 from repro.serving.paged import PagePool, pages_needed
 from repro.serving.prefix import (ParkedSession, PrefixStore, SessionStore,
@@ -274,11 +275,13 @@ class SlotPayload:
 class TierEngine:
     def __init__(self, model, params, serving: ServingConfig = ServingConfig(),
                  eos_id: int = 2, sample_temp: float = 0.0, seed: int = 0,
-                 device=None):
+                 device=None, tier: str = ""):
         """``device`` pins the engine — params, KV cache, sampling keys —
         to one ``jax.Device`` (replicas of a pool, one per chip); every
-        jitted step then runs there. None keeps JAX's default device."""
+        jitted step then runs there. None keeps JAX's default device.
+        ``tier`` names the tier served (a span field)."""
         self.device = device
+        self.tier = tier
         with jax.default_device(device):
             self._build(model, params, serving, eos_id, sample_temp, seed)
         if device is not None:
@@ -340,8 +343,9 @@ class TierEngine:
         self.accepted_tokens = 0
         self.spec_rounds = 0
         # cluster-runtime hooks: admission + per-token streaming callbacks
-        # (rid, t) and (rid, token, t); None = standalone engine
-        self.on_admit: Optional[Callable[[int, float], None]] = None
+        # (rid, t_admit, t) and (rid, token, t): t_admit is when the job
+        # left ``waiting``, t its first token; None = standalone engine
+        self.on_admit: Optional[Callable[[int, float, float], None]] = None
         self.on_token: Optional[Callable[[int, int, float], None]] = None
         # warm-admission + session-park hooks: (rid, kind, cached, suffix)
         # with kind in {"prefix", "resume"}, and (rid, sid)
@@ -1031,6 +1035,7 @@ class TierEngine:
             if plan.get("sid"):
                 self.sessions.resume(plan["sid"])  # rows consumed this turn
             del self.waiting[i]
+            job["t_admit"] = time.monotonic()
             self._admit_warm(job, slot, plan)
 
     def _reserve_warm(self, job: Dict[str, Any],
@@ -1236,25 +1241,26 @@ class TierEngine:
         bit-identical to what a fusion-local prefill would compute, so
         routing an image off the fusion tier never changes the generated
         tokens; only the compact embeddings travel."""
-        img = np.asarray(image)
-        p = num_patches or self.cfg.num_patches
-        fd = frontend_dim or self.cfg.frontend_dim
-        key = (p, fd, int(img.size))
-        fn = self._encode_jits.get(key)
-        if fn is None:
-            need = p * fd
-            rep = max(1, int(np.ceil(need / max(img.size, 1))))
+        with obs.span("moa.engine.encode"):
+            img = np.asarray(image)
+            p = num_patches or self.cfg.num_patches
+            fd = frontend_dim or self.cfg.frontend_dim
+            key = (p, fd, int(img.size))
+            fn = self._encode_jits.get(key)
+            if fn is None:
+                need = p * fd
+                rep = max(1, int(np.ceil(need / max(img.size, 1))))
 
-            def _enc(x):
-                flat = x.reshape(-1).astype(jnp.float32) / 255.0
-                return jnp.tile(flat, rep)[:need].reshape(p, fd)
+                def _enc(x):
+                    flat = x.reshape(-1).astype(jnp.float32) / 255.0
+                    return jnp.tile(flat, rep)[:need].reshape(p, fd)
 
-            fn = jax.jit(_enc)
-            self._encode_jits[key] = fn
-        out = np.asarray(fn(jnp.asarray(img)))
-        self.encode_tokens += p
-        self.journal.append(("encode", {"patches": p}))
-        return out
+                fn = jax.jit(_enc)
+                self._encode_jits[key] = fn
+            out = np.asarray(fn(jnp.asarray(img)))
+            self.encode_tokens += p
+            self.journal.append(("encode", {"patches": p}))
+            return out
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self.slots):
@@ -1486,7 +1492,8 @@ class TierEngine:
                 self.on_warm(st.rid, kind, cached, charged)
         self.journal.append(("admit", {"rid": st.rid, "slot": slot}))
         if self.on_admit is not None:
-            self.on_admit(st.rid, st.t_first_token)
+            self.on_admit(st.rid, job.get("t_admit", st.t_first_token),
+                          st.t_first_token)
         if self.on_token is not None:
             self.on_token(st.rid, int(first), st.t_first_token)
         self._store_prefixes(slot, job)
@@ -1908,26 +1915,29 @@ class TierEngine:
     # -- admission ----------------------------------------------------------
 
     def _admit(self) -> None:
-        # page pressure is re-evaluated every admission pass: pages freed by
-        # finished slots (or store eviction) clear it, a starved reservation
-        # below re-raises it and the next fused block splits early
-        self._page_pressure = False
-        if any(j.get("deadline") is not None for j in self.waiting):
-            # EDF admission: earliest deadline first, FIFO among ties /
-            # deadline-free requests (stable sort keeps submit order)
-            self.waiting.sort(key=lambda j: (
-                j["deadline"] if j.get("deadline") is not None
-                else float("inf"), j["t"]))
-        # warm admissions first (EDF order within them): a prompt extending
-        # a parked session or a stored prefix copies the cached rows and
-        # prefills only its suffix; everything else falls through cold
-        if self.waiting and (self.sessions.enabled
-                             or self.prefix_store.enabled):
-            self._admit_warm_hits()
-        if self.fused_steps <= 1 or not self.serving.bucket_prefill:
-            self._admit_legacy()
-        else:
-            self._admit_bucketed()
+        with obs.span("moa.engine.admit"):
+            # page pressure is re-evaluated every admission pass: pages
+            # freed by finished slots (or store eviction) clear it, a
+            # starved reservation below re-raises it and the next fused
+            # block splits early
+            self._page_pressure = False
+            if any(j.get("deadline") is not None for j in self.waiting):
+                # EDF admission: earliest deadline first, FIFO among ties /
+                # deadline-free requests (stable sort keeps submit order)
+                self.waiting.sort(key=lambda j: (
+                    j["deadline"] if j.get("deadline") is not None
+                    else float("inf"), j["t"]))
+            # warm admissions first (EDF order within them): a prompt
+            # extending a parked session or a stored prefix copies the
+            # cached rows and prefills only its suffix; everything else
+            # falls through cold
+            if self.waiting and (self.sessions.enabled
+                                 or self.prefix_store.enabled):
+                self._admit_warm_hits()
+            if self.fused_steps <= 1 or not self.serving.bucket_prefill:
+                self._admit_legacy()
+            else:
+                self._admit_bucketed()
 
     def _admit_legacy(self) -> None:
         while self.waiting:
@@ -1945,6 +1955,7 @@ class TierEngine:
                     return
                 self._assign_pages(slot, pages)
             job = self.waiting.pop(0)
+            job["t_admit"] = time.monotonic()
             toks = job["tokens"][None]  # (1, S)
             batch = {"tokens": jnp.asarray(toks, jnp.int32)}
             for k, v in job["extras"].items():
@@ -1981,6 +1992,9 @@ class TierEngine:
             if not jobs:
                 return
         del self.waiting[:len(jobs)]
+        now = time.monotonic()
+        for job in jobs:
+            job["t_admit"] = now
         pad_ok = self.cfg.family in _PADDED_FAMILIES
         groups: Dict[tuple, List[dict]] = {}
         for j, job in enumerate(jobs):
@@ -2015,33 +2029,37 @@ class TierEngine:
             n = len(job["tokens"])
             toks[i, :n] = job["tokens"]
             lengths[i] = n
-        batch: Dict[str, Any] = {"tokens": jnp.asarray(toks)}
-        for k in jobs[0]["extras"]:
-            batch[k] = jnp.asarray(np.stack(
-                [np.asarray((jobs[i] if i < r else jobs[0])["extras"][k])
-                 for i in range(rp)]))
-        if int(lengths.min()) < bucket:  # any row right-padded
-            batch["lengths"] = jnp.asarray(lengths)
         prefix = self._prompt_prefix(jobs[0]["extras"])
-        total = lengths + prefix
-        for job, slot in zip(jobs, slots):
-            pages = job.pop("_pages", None)
-            if pages is not None:
-                self._assign_pages(slot, pages)
-        slots_arr = np.asarray(slots + [slots[0]] * (rp - r), np.int32)
-        if self._pt is not None:
-            # pad rows replicate row 0's page table too — their duplicate
-            # scatters write identical content to the same pages
-            pt_rows = self._pt[slots_arr]
-            npg = max(1, max(len(self._slot_pages[s]) for s in slots))
-            logits, self.cache = self._prefill_insert(
-                self.params, self.cache, batch, jnp.asarray(slots_arr),
-                jnp.asarray(total), jnp.asarray(pt_rows), npg)
-        else:
-            logits, self.cache = self._prefill_insert(
-                self.params, self.cache, batch, jnp.asarray(slots_arr),
-                jnp.asarray(total))
-        logits = np.asarray(logits)  # one host sync per admitted group
+        with obs.span("moa.engine.prefill", tier=self.tier,
+                      lengths=lengths[:r], patches=prefix, bucket=bucket):
+            batch: Dict[str, Any] = {"tokens": jnp.asarray(toks)}
+            for k in jobs[0]["extras"]:
+                batch[k] = jnp.asarray(np.stack(
+                    [np.asarray((jobs[i] if i < r else jobs[0])["extras"][k])
+                     for i in range(rp)]))
+            if int(lengths.min()) < bucket:  # any row right-padded
+                batch["lengths"] = jnp.asarray(lengths)
+            total = lengths + prefix
+            for job, slot in zip(jobs, slots):
+                pages = job.pop("_pages", None)
+                if pages is not None:
+                    self._assign_pages(slot, pages)
+            slots_arr = np.asarray(slots + [slots[0]] * (rp - r), np.int32)
+            if self._pt is not None:
+                # pad rows replicate row 0's page table too — their
+                # duplicate scatters write identical content to the same
+                # pages
+                pt_rows = self._pt[slots_arr]
+                npg = max(1, max(len(self._slot_pages[s]) for s in slots))
+                logits, self.cache = self._prefill_insert(
+                    self.params, self.cache, batch, jnp.asarray(slots_arr),
+                    jnp.asarray(total), jnp.asarray(pt_rows), npg)
+            else:
+                logits, self.cache = self._prefill_insert(
+                    self.params, self.cache, batch, jnp.asarray(slots_arr),
+                    jnp.asarray(total))
+            with obs.span("moa.engine.prefill.read"):
+                logits = np.asarray(logits)  # one host sync per group
         for i, (job, slot) in enumerate(zip(jobs, slots)):
             self._start_seq(job, slot, int(total[i]), logits[i])
 
@@ -2068,66 +2086,75 @@ class TierEngine:
 
     def step(self) -> int:
         """Admit + one decode block for all active slots. Returns #active."""
-        t_in = time.monotonic()
-        self._admit()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return 0
-        if self.fused_steps <= 1:
-            n = self._step_legacy(active)
+        with obs.span("moa.engine.step"):
+            t_in = time.monotonic()
+            self._admit()
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            if not active:
+                return 0
+            if self.fused_steps <= 1:
+                n = self._step_legacy(active)
+                self._throttle_sleep(t_in)
+                return n
+            b = len(self.slots)
+            tokens = np.zeros((b,), np.int32)
+            positions = np.zeros((b,), np.int32)
+            budgets = np.zeros((b,), np.int32)
+            for i in active:
+                st = self.slots[i]
+                tokens[i] = st.generated[-1]
+                positions[i] = self.positions[i]
+                budgets[i] = max(0, st.max_new - len(st.generated))
+            k = self.fused_steps
+            if self.pool is not None and self._page_pressure and self.waiting:
+                # continuous admission under page pressure: split the fused
+                # block at the earliest point a slot can finish (and free
+                # its pages), so starved requests admit mid-block instead
+                # of waiting out a full K steps. k is a jit-static arg —
+                # each distinct split length is one cached trace on the
+                # power-of-two budget ladder of remaining tokens
+                rem = min(max(1, self.slots[i].max_new
+                              - len(self.slots[i].generated))
+                          for i in active)
+                k = max(1, min(k, rem))
+            teff = self.serving.max_seq
+            if self._ctx_buckets:
+                # smallest bucket covering every position the block can
+                # write; ladder = {2^n, 1.5*2^n} so the attended width
+                # tracks the live context within ~33% (each bucket is one
+                # cached trace)
+                teff = self._context_bucket(int(positions.max()) + k + 1)
+            with obs.span("moa.engine.block", tier=self.tier,
+                          positions=positions, budgets=budgets, k=k):
+                block, self.cache, self._keys = self._fused(
+                    self.params, self.cache, self._keys, jnp.asarray(tokens),
+                    jnp.asarray(positions), jnp.asarray(budgets), teff, k)
+                with obs.span("moa.engine.block.read"):
+                    # the ONLY host sync: (B, K) per K tokens
+                    block = np.asarray(block)
+            now = time.monotonic()
+            with obs.span("moa.engine.emit"):
+                for i in active:
+                    st = self.slots[i]
+                    if st is None:
+                        continue  # cancelled mid-block by an on_token hook
+                    for j in range(k):
+                        nxt = int(block[i, j])
+                        st.generated.append(nxt)
+                        self.decode_tokens += 1
+                        self.positions[i] += 1
+                        if self.on_token is not None:
+                            self.on_token(st.rid, nxt, now)
+                        hit_cap = self.positions[i] + 1 >= self.serving.max_seq
+                        if (nxt == self.eos_id
+                                or len(st.generated) >= st.max_new
+                                or hit_cap):
+                            self._finish_slot(i, now)
+                            break
+            self.steps += 1
+            self.last_heartbeat = now
             self._throttle_sleep(t_in)
-            return n
-        b = len(self.slots)
-        tokens = np.zeros((b,), np.int32)
-        positions = np.zeros((b,), np.int32)
-        budgets = np.zeros((b,), np.int32)
-        for i in active:
-            st = self.slots[i]
-            tokens[i] = st.generated[-1]
-            positions[i] = self.positions[i]
-            budgets[i] = max(0, st.max_new - len(st.generated))
-        k = self.fused_steps
-        if self.pool is not None and self._page_pressure and self.waiting:
-            # continuous admission under page pressure: split the fused
-            # block at the earliest point a slot can finish (and free its
-            # pages), so starved requests admit mid-block instead of
-            # waiting out a full K steps. k is a jit-static arg — each
-            # distinct split length is one cached trace on the power-of-two
-            # budget ladder of remaining tokens
-            rem = min(max(1, self.slots[i].max_new
-                          - len(self.slots[i].generated)) for i in active)
-            k = max(1, min(k, rem))
-        teff = self.serving.max_seq
-        if self._ctx_buckets:
-            # smallest bucket covering every position the block can write;
-            # ladder = {2^n, 1.5*2^n} so the attended width tracks the live
-            # context within ~33% (each bucket is one cached trace)
-            teff = self._context_bucket(int(positions.max()) + k + 1)
-        block, self.cache, self._keys = self._fused(
-            self.params, self.cache, self._keys, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(budgets), teff, k)
-        block = np.asarray(block)  # the ONLY host sync: (B, K) per K tokens
-        now = time.monotonic()
-        for i in active:
-            st = self.slots[i]
-            if st is None:
-                continue  # cancelled mid-block by an on_token callback
-            for j in range(k):
-                nxt = int(block[i, j])
-                st.generated.append(nxt)
-                self.decode_tokens += 1
-                self.positions[i] += 1
-                if self.on_token is not None:
-                    self.on_token(st.rid, nxt, now)
-                hit_cap = self.positions[i] + 1 >= self.serving.max_seq
-                if (nxt == self.eos_id or len(st.generated) >= st.max_new
-                        or hit_cap):
-                    self._finish_slot(i, now)
-                    break
-        self.steps += 1
-        self.last_heartbeat = now
-        self._throttle_sleep(t_in)
-        return len(active)
+            return len(active)
 
     def _step_legacy(self, active: List[int]) -> int:
         """Per-token path: one dispatch + one logits transfer per token."""

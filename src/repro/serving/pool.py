@@ -468,7 +468,7 @@ def build_engine_pools(topology, serving, configs=None,
         if transport == "process":
             trs: List = [ProcessTransport(ReplicaSpec(
                 model=tier.model, serving=sv, param_seed=i,
-                name=f"{tier.name}/{r}"))
+                name=f"{tier.name}/{r}", tier=tier.name))
                 for r in range(n)]
         else:
             from repro.serving.engine import TierEngine  # local: no cycle
@@ -479,7 +479,8 @@ def build_engine_pools(topology, serving, configs=None,
                     f"{len(devices)} devices: one replica per device")
             model, params = build_tier_model(cfgs[tier.name], i)
             trs = [LocalTransport(TierEngine(
-                model, params, sv, device=devices[r] if devices else None))
+                model, params, sv, device=devices[r] if devices else None,
+                tier=tier.name))
                 for r in range(n)]
         pools[tier.name] = EnginePool(tier.name, trs)
     return pools

@@ -35,6 +35,7 @@ from typing import Callable, Dict, List, Optional, Protocol, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.config import (ClusterTopology, ModelConfig, ResilienceConfig,
                           ServingConfig, SpecConfig, TierSpec)
 from repro.core.request import Job, Outcome, Request, RequestRecord
@@ -270,13 +271,23 @@ class ClusterRuntime:
 
     # -- lifecycle: arrival ------------------------------------------------
 
+    def _now(self, t: float) -> float:
+        """Time of a lifecycle mark made now: the event's time on a virtual
+        clock; live, the monotonic clock, which has moved on by the scoring
+        and by however late the event was handled."""
+        return t if self.backend.virtual_clock else time.monotonic()
+
     def _on_arrival(self, ev: Event):
         req: Request = ev.payload["request"]
+        with obs.span("moa.runtime.arrival", rid=req.rid):
+            self._arrive(ev, req)
+
+    def _arrive(self, ev: Event, req: Request):
         rec = self.records.get(req.rid)
         if rec is None:
             rec = RequestRecord(rid=req.rid)
             self.records[req.rid] = rec
-        rec.mark("arrival")
+        rec.mark("arrival", t=ev.t)
         self._observe()
         decision = self.scheduler.route(req)
         # score cost: the modality-aware module runs on the edge CPU/NPU —
@@ -338,11 +349,12 @@ class ClusterRuntime:
                     reason=decision.reason + "+degraded")
                 rec.degraded = True
                 self.degraded_routes += 1
-        rec.mark("routed", fusion)
+        t_routed = self._now(ev.t + score_cost)
+        rec.mark("routed", fusion, t_routed)
         if rec.degraded:
-            rec.mark("degraded", fusion)
+            rec.mark("degraded", fusion, t_routed)
         if sticky is not None:
-            rec.mark("sticky", sticky)
+            rec.mark("sticky", sticky, t_routed)
         job = Job(request=req, decision=decision, fusion=fusion, tier=fusion,
                   t_start=ev.t, record=rec)
         # cross-tier speculative decoding: honor the scheduler's stamp only
@@ -366,7 +378,7 @@ class ClusterRuntime:
         for name in sorted(req.modalities):
             routed = decision.routes.get(name, fusion)
             if routed != fusion:
-                rec.mark(f"encode:{name}", routed)
+                rec.mark(f"encode:{name}", routed, t_routed)
         self.backend.encode(ev.t, job)
         # bytes that must cross a WAN: payloads of remote-routed modalities,
         # tallied per remote tier (their links transfer in parallel)
@@ -451,7 +463,7 @@ class ClusterRuntime:
         or "session" (parked-session payloads, installed before service) —
         all ride the SAME link stations, queueing behind each other."""
         if kind == "data":
-            job.record.mark("transfer", tier)
+            job.record.mark("transfer", tier, t)
         xfer = {"job": job, "tier": tier, "bytes": num_bytes, "kind": kind}
         job.pending_transfers += 1
         link = self.links[tier]
@@ -491,7 +503,7 @@ class ClusterRuntime:
         tier, job = xfer["tier"], xfer["job"]
         self._link_release(ev.t, tier)
         job.pending_transfers -= 1
-        job.record.mark("timeout", tier)
+        job.record.mark("timeout", tier, ev.t)
         self._note_failure(ev.t, job, tier)
         kind = xfer["kind"]
         if kind == "migrate":
@@ -578,7 +590,7 @@ class ClusterRuntime:
                 and t >= job.request.arrival_s + job.request.slo_s):
             self.fail_request(t, job, job.tier, "shed")
             return
-        job.record.mark("enqueue", job.tier)
+        job.record.mark("enqueue", job.tier, self._now(t))
         if "t_enqueue" not in job.payload:
             job.payload["t_enqueue"] = t
             job.record.wan_s = t - job.t_start
@@ -612,7 +624,7 @@ class ClusterRuntime:
                 clone = job.clone(tier=alt)
                 clone.hedged = True
                 job.hedged = True
-                job.record.mark("hedged", alt)
+                job.record.mark("hedged", alt, ev.t)
                 if not self._try_migrate(ev.t, job, clone, alt):
                     # donor died between the decision and the extract: the
                     # clone falls back to a fresh prefill on that tier
@@ -625,7 +637,7 @@ class ClusterRuntime:
         clone = job.clone(tier=alt)
         clone.hedged = True
         job.hedged = True
-        job.record.mark("hedged", alt)
+        job.record.mark("hedged", alt, ev.t)
         self._enqueue_service(ev.t, clone)
 
     # -- lifecycle: cross-tier KV migration --------------------------------
@@ -657,9 +669,9 @@ class ClusterRuntime:
         carrier.payload["migration_nbytes"] = nbytes
         rec = carrier.record
         if pre:
-            rec.mark(pre, src)
+            rec.mark(pre, src, t)
         carrier.tier = dst
-        rec.mark("migrate", dst)
+        rec.mark("migrate", dst, t)
         spec_s, spec_d = self.specs[src], self.specs[dst]
         if spec_d.is_remote:
             self._enqueue_link(t, dst, carrier, nbytes, kind="migrate")
@@ -685,7 +697,7 @@ class ClusterRuntime:
         if nbytes is None:
             return
         dst = job.tier
-        job.record.mark("session_move", dst)
+        job.record.mark("session_move", dst, t)
         self.session_moves += 1
         job.payload["session_pending"] = True
         spec_s, spec_d = self.specs[src], self.specs[dst]
@@ -746,7 +758,7 @@ class ClusterRuntime:
         have won the ``record.done`` race before calling)."""
         req = job.request
         rec = job.record
-        rec.mark("complete", tier)
+        rec.mark("complete", tier, req.arrival_s + latency_s)
         self.scheduler.observe(latency_s=latency_s)
         if rec.drafted_tokens > 0:
             # acceptance-rate feedback: the EWMA gates future speculation
@@ -792,7 +804,7 @@ class ClusterRuntime:
             return
         if self.health.record_failure(tier, self.rel(t)):
             if not job.record.done:
-                job.record.mark("quarantine", tier)
+                job.record.mark("quarantine", tier, t)
             if self.resilience.rescue_sessions:
                 self._rescue_sessions(t, tier)
 
@@ -809,7 +821,7 @@ class ClusterRuntime:
             self.fail_request(t, job, tier, "retries")
             return
         job.retries += 1
-        job.record.mark("retry", tier)
+        job.record.mark("retry", tier, t)
         res = self.resilience
         if self.health is not None and \
                 not self.health.admit(job.tier, self.rel(t)):
@@ -820,7 +832,7 @@ class ClusterRuntime:
                 job.tier = fb
                 job.record.degraded = True
                 self.degraded_routes += 1
-                job.record.mark("degraded", fb)
+                job.record.mark("degraded", fb, t)
         delay = 0.0
         if res.retry_backoff:
             delay = retry_backoff_s(res, job.request.rid, job.retries)
@@ -848,7 +860,7 @@ class ClusterRuntime:
         if rec.done:
             return
         rec.done = True
-        rec.mark("shed" if reason == "shed" else "failed", tier)
+        rec.mark("shed" if reason == "shed" else "failed", tier, t)
         if reason == "shed":
             self.shed_count += 1
         else:
@@ -1427,7 +1439,7 @@ class AnalyticBackend:
                 rec = job.record
                 rec.warm = kind
                 rec.warm_tokens += cached
-                rec.mark(kind, job.tier)
+                rec.mark(kind, job.tier, t)
                 if kind == "resume":
                     self.resumed_sessions += 1
                 else:
@@ -1448,13 +1460,14 @@ class AnalyticBackend:
                                       "spec_draft_hbm", "spec_draft_s")}
             else:
                 job.payload.pop("spec_stats", None)
-        job.record.mark("serve", job.tier)
+        job.record.admit(t)
+        job.record.mark("serve", job.tier, t)
         if job.payload.get("spec_stats"):
             # one draft/verify triplet per request (not per round) so the
             # analytic trace matches the live co-drive's marks
             spx = job.payload["speculate"]
-            job.record.mark("draft", spx["draft"])
-            job.record.mark("verify", job.tier)
+            job.record.mark("draft", spx["draft"], t)
+            job.record.mark("verify", job.tier, t)
         job.payload["t_serve"] = t
         self.active[job.tier].append(job)
         sec = job.payload["service_s"]
@@ -1541,7 +1554,7 @@ class AnalyticBackend:
             # (counters only, like off-fusion ``encode`` — drafting rides
             # between the draft tier's own decode steps)
             spx = job.payload["speculate"]
-            job.record.mark("accept", tier)
+            job.record.mark("accept", tier, ev.t)
             job.record.drafted_tokens += int(sstats["spec_drafted"])
             job.record.accepted_tokens += int(sstats["spec_accepted"])
             dst = self.stations.get(spx["draft"])
@@ -1649,7 +1662,11 @@ class LiveBackend:
         from repro.serving.pool import EnginePool
         from repro.serving.transport import LocalTransport
 
-        # accept prebuilt pools or bare engines (wrapped 1:1)
+        # accept prebuilt pools or bare engines (wrapped 1:1); a bare
+        # engine built without a tier name serves the one it is keyed by
+        for t, v in engines.items():
+            if not isinstance(v, EnginePool) and not v.tier:
+                v.tier = t
         self.pools: Dict[str, EnginePool] = {
             t: (v if isinstance(v, EnginePool)
                 else EnginePool(t, [LocalTransport(v)]))
@@ -1743,11 +1760,12 @@ class LiveBackend:
     # -- engine callbacks ---------------------------------------------------
 
     def _make_on_admit(self, tier: str):
-        def on_admit(rid: int, t: float):
+        def on_admit(rid: int, t_admit: float, t: float):
             job = self._inflight[tier].get(rid)
             if job is not None and not job.in_service:
                 job.in_service = True
-                job.record.mark("serve", tier)
+                job.record.admit(t_admit)
+                job.record.mark("serve", tier, t)
         return on_admit
 
     def _make_on_warm(self, tier: str):
@@ -2221,8 +2239,8 @@ class LiveBackend:
                 dpool.cancel(donor.request.rid)
             self._inflight[donor.tier].pop(donor.request.rid, None)
         rec = carrier.record
-        rec.mark("enqueue", tier)
-        rec.mark("serve", tier)
+        rec.mark("enqueue", tier, t)
+        rec.mark("serve", tier, t)
         carrier.in_service = True
         self._inflight[tier][carrier.request.rid] = carrier
         if self._chaos:
@@ -2359,6 +2377,10 @@ class LiveBackend:
         return out
 
     def advance(self) -> bool:
+        with obs.span("moa.runtime.advance"):
+            return self._advance()
+
+    def _advance(self) -> bool:
         plan = self.rt.plan
         if plan is not None and self.rt.t0 is not None:
             # slow-node windows: throttle the replicas' step cadence while
@@ -2395,6 +2417,7 @@ class LiveBackend:
                     dt = min(dt, max(ddl - time.monotonic(), 0.0))
                 cap = self._idle_cap_s
                 if dt > 0:
-                    time.sleep(min(dt, cap) if cap > 0 else dt)
+                    with obs.span("moa.runtime.doze"):
+                        time.sleep(min(dt, cap) if cap > 0 else dt)
             return True
         return any(self._inflight[t] for t in self._inflight)

@@ -80,7 +80,8 @@ def build_cluster_engines(topology: ClusterTopology,
     engines = {}
     for i, tier in enumerate(topology.tiers):
         model, params = build_tier_model(cfgs[tier.name], i)
-        engines[tier.name] = TierEngine(model, params, serving)
+        engines[tier.name] = TierEngine(model, params, serving,
+                                        tier=tier.name)
     return engines
 
 

@@ -57,7 +57,7 @@ DEFAULT_MAX_FRAME_BYTES = 256 * 1024 * 1024
 # how many sequenced frames a sender keeps for gap replay
 OUTBOX_DEPTH = 512
 
-# event tuples streamed from a replica: ("admit", rid, t),
+# event tuples streamed from a replica: ("admit", rid, t_admit, t),
 # ("token", rid, token, t), ("warm", rid, kind, cached, suffix),
 # ("park", rid, sid)
 EVENT_KINDS = ("admit", "token", "warm", "park")
@@ -277,6 +277,7 @@ class ReplicaSpec:
     sample_temp: float = 0.0
     seed: int = 0
     name: str = "replica"
+    tier: str = ""  # the tier served (a span field)
 
 
 def _prefix_hit_len(store, tokens: np.ndarray, extras_fp: bytes) -> int:
@@ -341,7 +342,7 @@ class LocalTransport:
             self.engine.on_park = on_park
         else:
             self.engine.on_admit = \
-                lambda rid, t: self._gsend("ev", ("admit", rid, t))
+                lambda rid, ta, t: self._gsend("ev", ("admit", rid, ta, t))
             self.engine.on_token = \
                 lambda rid, tok, t: self._gsend("ev", ("token", rid, tok, t))
             self.engine.on_warm = \
@@ -405,7 +406,7 @@ class LocalTransport:
                     continue
                 ev = payload
                 if ev[0] == "admit" and on_admit:
-                    on_admit(ev[1], ev[2])
+                    on_admit(ev[1], ev[2], ev[3])
                 elif ev[0] == "token" and on_token:
                     on_token(ev[1], ev[2], ev[3])
                 elif ev[0] == "warm" and on_warm:
@@ -547,7 +548,8 @@ def _worker_main(conn, spec_raw: bytes) -> None:
         model = build_model(cfg)
         eng = TierEngine(model, model.init(jax.random.PRNGKey(spec.param_seed)),
                          spec.serving, eos_id=spec.eos_id,
-                         sample_temp=spec.sample_temp, seed=spec.seed)
+                         sample_temp=spec.sample_temp, seed=spec.seed,
+                         tier=spec.tier)
     except Exception as e:  # construction failed: report, don't hang
         try:
             conn.send_bytes(msg_to_bytes("died", f"build: {e!r}"))
@@ -556,7 +558,7 @@ def _worker_main(conn, spec_raw: bytes) -> None:
         return
 
     events: List[tuple] = []
-    eng.on_admit = lambda rid, t: events.append(("admit", rid, t))
+    eng.on_admit = lambda rid, ta, t: events.append(("admit", rid, ta, t))
     eng.on_token = lambda rid, tok, t: events.append(("token", rid, tok, t))
     eng.on_warm = lambda rid, k, c, s: events.append(("warm", rid, k, c, s))
     eng.on_park = lambda rid, sid: events.append(("park", rid, sid))
@@ -850,7 +852,7 @@ class ProcessTransport:
                 on_admit, on_token, on_warm, on_park = self._hooks
                 for ev in body:
                     if ev[0] == "admit" and on_admit:
-                        on_admit(ev[1], ev[2])
+                        on_admit(ev[1], ev[2], ev[3])
                     elif ev[0] == "token" and on_token:
                         on_token(ev[1], ev[2], ev[3])
                     elif ev[0] == "warm" and on_warm:

@@ -275,7 +275,7 @@ def _drive(transport, jobs, chaos_events):
     """Submit jobs and poll to completion; returns {rid: generated}."""
     events = []
     transport.wire_hooks(
-        lambda rid, t: events.append(("admit", rid)),
+        lambda rid, t_admit, t: events.append(("admit", rid)),
         lambda rid, tok, t: events.append(("token", rid, tok)),
         lambda rid, k, c, s: None, lambda rid, sid: None)
     if chaos_events is not None:
