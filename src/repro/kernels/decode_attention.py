@@ -30,7 +30,7 @@ NEG_INF = -1e30
 
 def _kernel(posq_ref, q_ref, k_ref, v_ref, posc_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, window: Optional[int],
-            n_t: int):
+            n_t: int, last_ref=None):
     b = pl.program_id(0)
     it = pl.program_id(1)
 
@@ -40,29 +40,37 @@ def _kernel(posq_ref, q_ref, k_ref, v_ref, posc_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    pos_q = posq_ref[b]                        # SMEM scalar
-    pos_c = posc_ref[0, 0]                     # (1, bt) int32
-    d = pos_q - pos_c
-    ok = (pos_c >= 0) & (d >= 0)
-    if window is not None:
-        ok &= d < window
+    def _update():
+        pos_q = posq_ref[b]                    # SMEM scalar
+        pos_c = posc_ref[0, 0]                 # (1, bt) int32
+        d = pos_q - pos_c
+        ok = (pos_c >= 0) & (d >= 0)
+        if window is not None:
+            ok &= d < window
 
-    for j in range(q_ref.shape[1]):            # KV heads (static)
-        k = k_ref[0, :, j, :]                  # (bt, hd)
-        v = v_ref[0, :, j, :]
-        q = q_ref[0, j].astype(k.dtype)        # (G, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(ok, s, NEG_INF)          # (G, bt)
-        m_prev = m_ref[j]                      # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        acc_ref[j] = acc_ref[j] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[j] = m_new
-        l_ref[j] = l_ref[j] * corr + jnp.sum(p, axis=1, keepdims=True)
+        for j in range(q_ref.shape[1]):        # KV heads (static)
+            k = k_ref[0, :, j, :]              # (bt, hd)
+            v = v_ref[0, :, j, :]
+            q = q_ref[0, j].astype(k.dtype)    # (G, hd)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, NEG_INF)      # (G, bt)
+            m_prev = m_ref[j]                  # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            acc_ref[j] = acc_ref[j] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[j] = m_new
+            l_ref[j] = l_ref[j] * corr + jnp.sum(p, axis=1, keepdims=True)
+
+    if last_ref is None:
+        _update()
+    else:
+        # past the row's last live block every key is masked, so the update
+        # would add p = 0 under corr = 1: skipping it changes no bit
+        pl.when(it <= last_ref[b])(_update)
 
     @pl.when(it == n_t - 1)
     def _finalize():
@@ -70,18 +78,19 @@ def _kernel(posq_ref, q_ref, k_ref, v_ref, posc_ref, o_ref,
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_kernel(pages_ref, posq_ref, *refs, **kw):
+def _paged_kernel(pages_ref, posq_ref, last_ref, *refs, **kw):
     # the page table is consumed by the k/v index_maps (scalar prefetch);
-    # the block body is the exact dense flash-decode update
+    # the block body is the dense flash-decode update, up to ``last``
     del pages_ref
-    _kernel(posq_ref, *refs, **kw)
+    _kernel(posq_ref, *refs, last_ref=last_ref, **kw)
 
 
-def _call(kernel, prefetch, qg, k, v, posc, kv_block, kv_map, window,
-          interpret):
+def _call(kernel, prefetch, qg, k, v, posc, kv_block, kv_map, pos_map,
+          window, interpret):
     """pallas_call plumbing shared by the dense and paged kernels: grid
     (B, nT) over ``posc``'s (B, nT, 1, bt) position blocks, scalar-prefetch
-    operands ``prefetch`` first, and the kv blocks placed by ``kv_map``."""
+    operands ``prefetch`` first, the kv blocks placed by ``kv_map`` and the
+    position blocks by ``pos_map``."""
     b, kh, g, hd = qg.shape
     n_t = posc.shape[1]
     q_spec = pl.BlockSpec((1, kh, g, hd), lambda b_, it, *_: (b_, 0, 0, 0))
@@ -92,8 +101,7 @@ def _call(kernel, prefetch, qg, k, v, posc, kv_block, kv_map, window,
             q_spec,
             pl.BlockSpec(kv_block, kv_map),
             pl.BlockSpec(kv_block, kv_map),
-            pl.BlockSpec((1, 1, 1, posc.shape[-1]),
-                         lambda b_, it, *_: (b_, it, 0, 0)),
+            pl.BlockSpec((1, 1, 1, posc.shape[-1]), pos_map),
         ],
         out_specs=q_spec,
         scratch_shapes=[
@@ -131,6 +139,19 @@ def decode_attention_pallas_paged(q: jax.Array, k_pool: jax.Array,
     of the context is ever materialized. Entries past ``pos_cache``'s width
     (the partial last page) and null-page garbage carry pos = -1 and mask to
     an exact zero, like the dense kernel's empty slots.
+
+    Only pages up to each row's live bound are fetched and computed:
+    ``last[b]``, the highest logical page holding an entry with
+    ``0 <= pos <= pos_q[b]`` (0 if none), is reduced from ``pos_cache``
+    itself and prefetched beside the table. Grid steps past it repeat the
+    page index of step ``last[b]`` (so no DMA is issued) and skip the
+    update. That is exact: every key past the bound is masked, and a fully
+    masked page after a live one adds p = exp(NEG_INF - m) = 0 under
+    corr = 1, leaving (acc, m, l) bit-identical. Taking the bound from the
+    positions, not from a length, keeps it right for any page table:
+    shared prefix pages, sliding windows, garbage pages past the frontier.
+    A row with no live entry, whose output no caller reads, returns the
+    mean of its first page's values (the reference: of every entry's).
     """
     if interpret is None:
         interpret = auto_interpret()
@@ -144,12 +165,19 @@ def decode_attention_pallas_paged(q: jax.Array, k_pool: jax.Array,
         pos_cache = jnp.pad(pos_cache, ((0, 0), (0, n_p * page - t)),
                             constant_values=-1)
     qg = q[:, 0].reshape(b, kh, g, hd)
+    pos_q = pos_q.astype(jnp.int32)
     posc = pos_cache.astype(jnp.int32).reshape(b, n_p, 1, page)
-    out = _call(_paged_kernel,
-                (pages.astype(jnp.int32), pos_q.astype(jnp.int32)),
+    live = jnp.any((posc >= 0) & (posc <= pos_q[:, None, None, None]),
+                   axis=(2, 3))                                  # (B, NP)
+    last = jnp.max(jnp.where(live, jnp.arange(n_p, dtype=jnp.int32), 0),
+                   axis=1)
+    step = lambda b_, it, lst: jnp.minimum(it, lst[b_])
+    out = _call(_paged_kernel, (pages.astype(jnp.int32), pos_q, last),
                 qg, k_pool, v_pool, posc, (1, page, kh, hd),
-                lambda b_, it, pg, pq: (pg[b_, it], 0, 0, 0), window,
-                interpret)
+                lambda b_, it, pg, pq, lst:
+                    (pg[b_, step(b_, it, lst)], 0, 0, 0),
+                lambda b_, it, pg, pq, lst: (b_, step(b_, it, lst), 0, 0),
+                window, interpret)
     return out.reshape(b, 1, h, hd)
 
 
@@ -194,7 +222,7 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
 
     qg = q[:, 0].reshape(b, kh, g, hd)  # (B,K,G,hd), head h = kh_idx*g + g_idx
     posc = pos_cache.astype(jnp.int32).reshape(b, n_t, 1, block_t)
+    block = lambda b_, it, pq: (b_, it, 0, 0)
     out = _call(_kernel, (pos_q.astype(jnp.int32),), qg, k_cache, v_cache,
-                posc, (1, block_t, kh, hd),
-                lambda b_, it, pq: (b_, it, 0, 0), window, interpret)
+                posc, (1, block_t, kh, hd), block, block, window, interpret)
     return out.reshape(b, 1, h, hd)

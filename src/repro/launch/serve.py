@@ -70,6 +70,18 @@ def span_table(spans):
                   key=lambda r: -r[3])
 
 
+def paged_live_share(spans):
+    """``(live, grid, blocks)``: the paged decode kernel's computed page
+    steps and grid page steps summed over the ``moa.engine.block`` spans
+    that carry them, and how many did; None where none did."""
+    blocks = [sp.fields for sp in spans if sp.name == "moa.engine.block"
+              and "pages_grid" in sp.fields]
+    if not blocks:
+        return None
+    return (sum(f["pages_live"] for f in blocks),
+            sum(f["pages_grid"] for f in blocks), len(blocks))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=8)
@@ -322,8 +334,14 @@ def main() -> None:
         obs.disable()
         print(f"spans (profile in {args.trace_dir}): name count total_s "
               f"self_s")
-        for name, n, total, own in span_table(obs.take()):
+        spans = obs.take()
+        for name, n, total, own in span_table(spans):
             print(f"  {name:32s} {n:6d} {total:9.4f} {own:9.4f}")
+        share = paged_live_share(spans)
+        if share is not None:
+            live, grid, n = share
+            print(f"paged live share = {live / grid:.4f} ({live} of {grid} "
+                  f"page steps computed over {n} blocks)")
     per_tier = {}
     for r in results:
         per_tier[r.tier] = per_tier.get(r.tier, 0) + 1

@@ -86,6 +86,12 @@ def enable() -> None:
     _on = True
 
 
+def enabled() -> bool:
+    """Whether spans are on: a field worth computing only for a kept span
+    is computed under this check."""
+    return _on
+
+
 def disable() -> None:
     global _on
     _on = False

@@ -81,6 +81,25 @@ def _next_bucket(n: int, lo: int = 16) -> int:
     return b
 
 
+def block_pages(positions: np.ndarray, budgets: np.ndarray, k: int,
+                teff: int, page: int, max_seq: int) -> tuple:
+    """``(pages_grid, pages_live)`` of one fused block's paged decode: the
+    page steps of the kernel's grid over its ``k`` steps, and those it
+    computes, up to each row's last live page. A row alive at the block's
+    start (``budgets > 0``) writes position ``positions + min(j, budgets)``
+    at step ``j`` (it stops at its budget or the cache's end) and computes
+    ``pos // page + 1`` pages. Any other row counts one, its dead write's
+    entry at page 0; a slot freed earlier whose stale write index lies in
+    the bucket computes up to that index's page, which is left out."""
+    n_pg = -(-teff // page)
+    pos = positions[:, None] + np.minimum(np.arange(k)[None],
+                                          budgets[:, None])
+    live = np.where(budgets[:, None] > 0,
+                    np.minimum(np.minimum(pos, max_seq - 1) // page + 1,
+                               n_pg), 1)
+    return k * len(positions) * n_pg, int(live.sum())
+
+
 @dataclass
 class SeqState:
     rid: int
@@ -2124,8 +2143,15 @@ class TierEngine:
                 # tracks the live context within ~33% (each bucket is one
                 # cached trace)
                 teff = self._context_bucket(int(positions.max()) + k + 1)
+            pages = {}
+            if self._pt is not None and obs.enabled():
+                grid, live = block_pages(
+                    positions, budgets, k, teff, self.serving.kv_page_size,
+                    self.serving.max_seq)
+                pages = {"pages_grid": grid, "pages_live": live}
             with obs.span("moa.engine.block", tier=self.tier,
-                          positions=positions, budgets=budgets, k=k):
+                          positions=positions, budgets=budgets, k=k,
+                          teff=teff, **pages):
                 block, self.cache, self._keys = self._fused(
                     self.params, self.cache, self._keys, jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(budgets), teff, k)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.models import attention
 
 RNG = np.random.default_rng(0)
 
@@ -121,31 +122,56 @@ def test_decode_attention_vs_ref(t, h, kh, hd, fill):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("b,h,kh,page,n_pt,width,window", [
-    (2, 8, 2, 16, 4, 64, None),   # GQA, every logical row in the table
-    (3, 4, 1, 16, 4, 50, None),   # MQA, ragged last page (pos width 50)
-    (2, 4, 2, 32, 3, 96, 40),     # sliding window
+@pytest.mark.parametrize("b,h,kh,page,n_pt,width,window,fills", [
+    pytest.param(2, 8, 2, 16, 4, 64, None, None,   # GQA, every row in table
+                 id="2-8-2-16-4-64-None"),
+    pytest.param(3, 4, 1, 16, 4, 50, None, None,   # MQA, ragged last page
+                 id="3-4-1-16-4-50-None"),
+    pytest.param(2, 4, 2, 32, 3, 96, 40, None,     # sliding window
+                 id="2-4-2-32-3-96-40"),
+    # ragged rows in one batch: no live entry, one live page, every page,
+    # and one ending mid-table (pages past each frontier hold NaN)
+    pytest.param(4, 8, 2, 16, 4, 64, None, (0, 9, 64, 33), id="ragged"),
+    pytest.param(3, 4, 1, 16, 4, 50, None, (50, 0, 16), id="ragged-tail"),
+    pytest.param(4, 4, 2, 32, 3, 96, 40, (0, 20, 96, 50),
+                 id="ragged-window"),
 ])
-def test_decode_attention_paged_vs_ref(b, h, kh, page, n_pt, width, window):
+def test_decode_attention_paged_vs_ref(b, h, kh, page, n_pt, width, window,
+                                       fills):
     """Pages are read THROUGH each row's table out of a shared pool, so the
-    kernel must equal dense decode over the gathered context."""
+    kernel must equal dense decode over the gathered context. Every row owns
+    its pages, and the pages wholly past a row's last live one hold NaN: a
+    kernel that read them would turn the row to NaN, so matching the
+    reference on the clean pool proves them unread. A row with no live
+    entry has no defined output (the engine discards it) beyond being
+    finite."""
     hd, n_pool = 64, 1 + b * n_pt
     q = _rand((b, 1, h, hd))
     kp = _rand((n_pool, page, kh, hd))
     vp = _rand((n_pool, page, kh, hd))
-    pages = np.stack([RNG.permutation(np.arange(1, n_pool))[:n_pt]
-                      for _ in range(b)]).astype(np.int32)
-    fill = RNG.integers(1, width + 1, size=b)
+    pages = RNG.permutation(np.arange(1, n_pool)).reshape(b, n_pt)
+    pages = pages.astype(np.int32)
+    fill = (RNG.integers(1, width + 1, size=b) if fills is None
+            else np.asarray(fills))
     pos = np.where(np.arange(width)[None] < fill[:, None],
                    np.arange(width)[None], -1).astype(np.int32)
-    pq = jnp.asarray(fill - 1, jnp.int32)
-    got = ops.decode_attention_paged(q, kp, vp, jnp.asarray(pages), pq,
+    pq = jnp.asarray(np.maximum(fill - 1, 0), jnp.int32)
+    last = np.maximum(-(-fill // page) - 1, 0)
+    past = pages[np.arange(n_pt)[None] > last[:, None]]
+    got = ops.decode_attention_paged(q, kp.at[past].set(jnp.nan),
+                                     vp.at[past].set(jnp.nan),
+                                     jnp.asarray(pages), pq,
                                      jnp.asarray(pos), window=window,
                                      interpret=True)
     ctx = lambda pool: pool[pages].reshape(b, n_pt * page, kh, hd)[:, :width]
     want = ref.decode_attention_ref(q, ctx(kp), ctx(vp), pq, jnp.asarray(pos),
                                     window=window)
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    live = fill > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    xla = attention.decode_attention_paged_xla(
+        q, kp, vp, jnp.asarray(pages), pq, jnp.asarray(pos), window=window)
+    np.testing.assert_allclose(got[live], xla[live], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
 
 
 def test_decode_attention_ring_buffer_semantics():

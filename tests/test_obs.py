@@ -21,7 +21,10 @@ from repro import obs
 from repro.config import ServingConfig, two_tier_topology
 from repro.data.synthetic import make_image
 from repro.launch import serve
-from repro.launch.serve import span_table
+from repro.launch.serve import paged_live_share, span_table
+from repro.models import build_model
+from repro.serving import engine as engine_mod
+from repro.serving.engine import TierEngine
 from repro.serving.tiers import ClusterServer, build_cluster_engines
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -102,9 +105,59 @@ def test_serve_trace_dir_profiles_and_prints_the_span_table(
     for name in ("moa.runtime.advance", "moa.scheduler.score.read",
                  "moa.engine.block", "moa.engine.prefill"):
         assert f"  {name} " in table, name
+    live, grid, blocks = map(int, re.search(
+        r"paged live share = [0-9.]+ \((\d+) of (\d+) page steps computed "
+        r"over (\d+) blocks\)", out).groups())
+    assert 0 < live <= grid and blocks > 0
     assert list(tmp_path.rglob("*.xplane.pb"))
     assert obs.span("moa.after") is obs.span("moa.after2")  # off again
     assert obs.take() == []
+
+
+def test_block_span_counts_the_paged_kernels_pages(family_model,
+                                                   monkeypatch):
+    """A paged block's ``pages_grid`` is k x rows x pages of its bucket
+    and ``pages_live`` the pages each row's kernel steps compute, counted
+    here by hand from the span's positions, budgets, k and teff. With
+    spans off the counts are never computed."""
+    cfg, params = family_model("dense")
+    page, max_seq = 32, 256
+    sv = ServingConfig(max_batch=4, max_seq=max_seq, paged=True,
+                       kv_page_size=page, fused_steps=8)
+    eng = TierEngine(build_model(cfg), params, sv, eos_id=-1)
+    rng = np.random.default_rng(0)
+    # a one-page row, a row ending mid-block, a three-page row, an empty slot
+    for rid, (n, max_new) in enumerate([(5, 40), (40, 3), (70, 40)]):
+        eng.submit(rid, rng.integers(4, 200, size=n).astype(np.int32),
+                   max_new=max_new)
+    calls = []
+    counted = engine_mod.block_pages
+    monkeypatch.setattr(engine_mod, "block_pages",
+                        lambda *a: calls.append(a) or counted(*a))
+    obs.take()
+    obs.enable()
+    try:
+        eng.step()
+    finally:
+        obs.disable()
+    (blk,) = [s for s in obs.take() if s.name == "moa.engine.block"]
+    f = blk.fields
+    positions, budgets, k, teff = (f["positions"], f["budgets"], f["k"],
+                                   f["teff"])
+    assert positions.tolist() == [5, 40, 70, 0]
+    assert budgets.tolist() == [39, 2, 39, 0] and k == 8 and teff == 96
+    assert f["pages_grid"] == 8 * 4 * 3
+    live = 0
+    for j in range(k):
+        for pos, budget in zip(positions.tolist(), budgets.tolist()):
+            live += (pos + min(j, budget)) // page + 1 if budget else 1
+    # rows of 1, 2 and 3 pages over 8 steps, the empty slot 1 a step
+    assert f["pages_live"] == live == 8 * (1 + 2 + 3 + 1)
+    assert paged_live_share([blk]) == (live, 96, 1)
+    assert len(calls) == 1
+
+    eng.step()  # spans off: no counts
+    assert len(calls) == 1 and obs.take() == []
 
 
 # -- a CPU serve through the cluster server ------------------------------------
